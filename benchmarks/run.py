@@ -47,6 +47,8 @@ def main(argv=None) -> int:
                          "for the whole run into DIR (CI artifact)")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_backfill, bench_layout_grid, bench_matcher,
                             bench_overhead, bench_query_concurrency,
                             bench_scale, bench_serve, bench_speedup,

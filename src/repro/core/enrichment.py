@@ -37,7 +37,7 @@ def bitmap_get(bm: np.ndarray, rule_id: int) -> np.ndarray:
 
 def to_bool_columns(bm: np.ndarray, num_rules: int) -> np.ndarray:
     """Pinot layout: (N, W) uint32 -> (N, num_rules) bool."""
-    bm = np.asarray(bm)
+    bm = np.ascontiguousarray(bm)     # device results may come back F-order
     N, W = bm.shape
     bits = np.unpackbits(bm.view(np.uint8).reshape(N, W, 4),
                          axis=-1, bitorder="little")       # (N, W, 32)
@@ -83,7 +83,7 @@ def from_sparse_ids(ids: np.ndarray, num_rules: int) -> np.ndarray:
 
 def popcount(bm: np.ndarray) -> np.ndarray:
     """(N, W) -> (N,) number of matched rules per record."""
-    bm = np.asarray(bm)
+    bm = np.ascontiguousarray(bm)
     return np.unpackbits(bm.view(np.uint8), axis=-1).sum(axis=-1)
 
 

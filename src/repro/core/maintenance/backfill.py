@@ -159,7 +159,7 @@ class BackfillWorker:
     def __init__(self, store: SegmentStore, bus: ControlBus,
                  object_store: ObjectStore, *, worker_id: str = "maint-0",
                  scheduler=None, backend: str = "dfa_ref",
-                 block_n: int = 256, interpret: bool = True,
+                 block_n: int = 256,
                  shard_index: int = 0, num_shards: int = 1,
                  leases: LeaseManager = None, rows_per_pass: int = None,
                  matcher_cache: dict = None):
@@ -170,7 +170,6 @@ class BackfillWorker:
         self.scheduler = scheduler
         self.backend = backend
         self.block_n = block_n
-        self.interpret = interpret
         if not 0 <= shard_index < max(num_shards, 1):
             raise ValueError(f"shard_index {shard_index} out of range for "
                              f"{num_shards} shards")
@@ -647,7 +646,8 @@ class BackfillWorker:
         self._mem_ckpts.pop(seg.segment_id, None)
         if seg.path is not None:
             try:
-                (seg.path / CKPT_NAME).unlink()
+                # most segments finish in one pass and never checkpoint
+                (seg.path / CKPT_NAME).unlink(missing_ok=True)
             except OSError as e:
                 telemetry.suppressed("maintenance.clear_checkpoint", e)
 
@@ -697,6 +697,5 @@ class BackfillWorker:
             bundle = compile_bundle(RuleSet(delta_rules),
                                     key[2])     # the matchable fields
             self._matchers[key] = build_matchers(
-                bundle, backend=self.backend, block_n=self.block_n,
-                interpret=self.interpret)
+                bundle, backend=self.backend, block_n=self.block_n)
         return self._matchers[key]

@@ -58,6 +58,19 @@ _RESPAWNS = telemetry.counter(
     help="Maintenance worker processes respawned under their old identity.")
 
 
+def device_refusal(what: str, instead: str):
+    """-> why ``what``, whose child processes build JAX matchers or
+    executors, cannot run here; None on the CPU backend.  A chip belongs to
+    one process, and this one has touched JAX."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return None
+    return (f"{what} cannot run on the {backend!r} backend: its worker "
+            f"processes would need the device this process holds; use "
+            f"{instead}")
+
+
 def _worker_main(cfg: dict, conn) -> None:
     """Child entry point (spawn target — module level, import-safe).
 
@@ -91,7 +104,7 @@ def _worker_main(cfg: dict, conn) -> None:
     worker = BackfillWorker(
         store, bus, ostore, worker_id=cfg["worker_id"],
         scheduler=scheduler, backend=cfg["backend"],
-        block_n=cfg["block_n"], interpret=cfg["interpret"],
+        block_n=cfg["block_n"],
         shard_index=cfg["shard_index"], num_shards=cfg["num_shards"],
         leases=leases, rows_per_pass=cfg["rows_per_pass"])
 
@@ -163,12 +176,16 @@ class ProcessMaintenancePool:
     def __init__(self, root, *, num_workers: int = 2, store=None,
                  objects_root=None, scheduler=None, policy=None,
                  backend: str = "dfa_ref", block_n: int = 256,
-                 interpret: bool = True, rows_per_pass: int = None,
+                 rows_per_pass: int = None,
                  worker_prefix: str = "maint", lease_ttl: float = 30.0,
                  segment_size: int = 100_000, index_fields: tuple = (),
                  recv_timeout: float = 120.0, respawn: bool = True):
         if num_workers < 1:
             raise ValueError(f"num_workers must be >= 1, got {num_workers}")
+        refusal = device_refusal("ProcessMaintenancePool",
+                                 "MaintenanceWorkerPool (thread workers)")
+        if refusal:
+            raise RuntimeError(refusal)
         self.root = Path(root)
         self.store = store
         if objects_root is None:
@@ -190,7 +207,7 @@ class ProcessMaintenancePool:
         self._ctx = mp.get_context("spawn")
         self._cfg_base = {
             "root": str(self.root), "objects_root": self.objects_root,
-            "backend": backend, "block_n": block_n, "interpret": interpret,
+            "backend": backend, "block_n": block_n,
             "rows_per_pass": rows_per_pass, "lease_ttl": float(lease_ttl),
             "segment_size": int(segment_size),
             "index_fields": tuple(index_fields),

@@ -59,7 +59,7 @@ class MaintenanceWorkerPool:
     def __init__(self, store, bus, object_store, *, num_workers: int = 2,
                  scheduler=None, leases: LeaseManager = None,
                  backend: str = "dfa_ref", block_n: int = 256,
-                 interpret: bool = True, rows_per_pass: int = None,
+                 rows_per_pass: int = None,
                  worker_prefix: str = "maint", lease_ttl: float = 30.0,
                  matcher_cache: dict = None):
         if num_workers < 1:
@@ -73,7 +73,7 @@ class MaintenanceWorkerPool:
             BackfillWorker(store, bus, object_store,
                            worker_id=f"{worker_prefix}-{i}",
                            scheduler=scheduler, backend=backend,
-                           block_n=block_n, interpret=interpret,
+                           block_n=block_n,
                            shard_index=i, num_shards=num_workers,
                            leases=self.leases, rows_per_pass=rows_per_pass,
                            matcher_cache=self._matcher_cache)

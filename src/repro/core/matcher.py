@@ -4,10 +4,16 @@ Wraps a compiled automaton (``core.automaton.CompiledEngine``) with device
 arrays and a jitted single-pass dispatch.  Engine *backends* select the
 TPU-native algorithm (DESIGN.md §2):
 
-    dfa        AC-DFA batch scan — paper-faithful default (Pallas kernel)
-    dfa_ref    pure-jnp oracle of the same
-    shift_or   bit-parallel shift-AND (literals <= 32 B) — beyond-paper
+    dfa        AC-DFA batch scan (Pallas kernel; CPU interpreter only)
+    dfa_ref    pure-jnp form of the same — the default, and the lane that
+               runs on the TPU
+    shift_or   bit-parallel shift-AND (literals <= 32 B; Pallas kernel,
+               CPU interpreter only) — beyond-paper
     parallel   associative-scan DFA (small automata) — beyond-paper
+
+Mosaic does not compile the ``dfa`` and ``shift_or`` kernels for TPU (see
+``TPU_REFUSED``), so those lanes raise when a matcher is built on a
+compiled backend rather than failing batch by batch behind the breaker.
 
 An ``EngineBundle`` groups one engine per record text field (paper §6.1 runs
 "one Pattern Matching Engine instance per text field") plus version metadata;
@@ -36,6 +42,7 @@ import numpy as np
 from repro.core import telemetry
 from repro.core.automaton import CompiledEngine, compile_rules, words_for_rules
 from repro.core.patterns import RuleSet
+from repro.kernels import interpret
 from repro.kernels.dfa_scan.ops import (dfa_scan, dfa_scan_fused,
                                         dfa_scan_selective, pack_delta_any)
 from repro.kernels.shift_or import ops as shift_or_ops
@@ -43,6 +50,29 @@ from repro.kernels.shift_or import ops as shift_or_ops
 BACKENDS = ("dfa", "dfa_ref", "dfa_selective", "shift_or", "parallel")
 # backends whose whole multi-field match can run as one fused device dispatch
 FUSED_BACKENDS = ("dfa", "dfa_ref", "parallel")
+# lanes whose Pallas kernel Mosaic refuses for TPU, with the compiler's words
+# (tests/test_tpu_compile.py keeps the record current)
+TPU_REFUSED = {
+    "dfa": "dfa_scan_fused_kernel: Mosaic lowers a gather only within one "
+           "vreg ('Only 2D gather is supported'; as a 2-D take_along_axis: "
+           "'Multiple source vregs along gather dimension'), and the DFA "
+           "table lookups span thousands",
+    "shift_or": "shift_or_kernel: its (256, Wb) table row gather does not "
+                "lower ('Shape mismatch in input, indices and output'; as a "
+                "2-D take_along_axis: 'Multiple source vregs along gather "
+                "dimension')",
+}
+
+
+def check_lane(backend: str) -> None:
+    """Raise when ``backend`` needs a Pallas kernel that the current
+    backend cannot compile (everything but the CPU interpreter, for the
+    lanes in ``TPU_REFUSED``)."""
+    if backend in TPU_REFUSED and not interpret():
+        raise NotImplementedError(
+            f"match backend {backend!r} cannot run on "
+            f"{jax.default_backend()!r}: {TPU_REFUSED[backend]}. "
+            f"Use backend='dfa_ref'.")
 
 # -- device->host accounting -------------------------------------------------
 # The enrich path must perform exactly ONE D2H transfer per batch; tests
@@ -102,12 +132,14 @@ class MatchEngine:
 
     def __init__(self, engine: CompiledEngine, *, backend: str = "dfa_ref",
                  ruleset: RuleSet = None, block_n: int = 256,
-                 interpret: bool = True, confirm_backend: str = "ref"):
+                 confirm_backend: str = "ref"):
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}")
+        check_lane(backend)
+        if backend == "dfa_selective" and confirm_backend == "pallas":
+            check_lane("dfa")
         self.backend = backend
         self.block_n = block_n
-        self.interpret = interpret
         self.confirm_backend = confirm_backend   # dfa_selective pass-2 engine
         self.engine = engine
         self.version = engine.version
@@ -139,14 +171,12 @@ class MatchEngine:
                                       self.engine.byte_classes,
                                       delta2=self._delta2,
                                       backend=self.confirm_backend,
-                                      block_n=self.block_n,
-                                      interpret=self.interpret)
+                                      block_n=self.block_n)
         data = jnp.asarray(data)
         if self.backend == "shift_or":
             bm = shift_or_ops.shift_or_match(data, self._shift_or,
                                              backend="pallas",
-                                             block_n=self.block_n,
-                                             interpret=self.interpret)
+                                             block_n=self.block_n)
             # shift_or packs exactly ceil(rules/32) words; widen to the bucket
             W = self.words
             if bm.shape[1] < W:
@@ -154,8 +184,7 @@ class MatchEngine:
             return bm
         backend = {"dfa": "pallas", "dfa_ref": "ref", "parallel": "parallel"}[self.backend]
         return dfa_scan(data, self._delta, self._emit, self._classes,
-                        backend=backend, block_n=self.block_n,
-                        interpret=self.interpret)
+                        backend=backend, block_n=self.block_n)
 
 
 @dataclass(frozen=True)
@@ -229,13 +258,11 @@ def compile_bundle(ruleset: RuleSet, fields) -> EngineBundle:
 
 
 def build_matchers(bundle: EngineBundle, *, backend: str = "dfa_ref",
-                   block_n: int = 256, interpret: bool = True,
-                   confirm_backend: str = "ref") -> dict:
+                   block_n: int = 256, confirm_backend: str = "ref") -> dict:
     """field -> MatchEngine, ready for StreamProcessor hot-swap."""
     rs = bundle.ruleset() if bundle.ruleset_json else None
     return {f: MatchEngine(bundle.engines[f], backend=backend, ruleset=rs,
-                           block_n=block_n, interpret=interpret,
-                           confirm_backend=confirm_backend)
+                           block_n=block_n, confirm_backend=confirm_backend)
             for f in bundle.fields}
 
 
@@ -275,14 +302,14 @@ class FusedMatcher:
     """
 
     def __init__(self, bundle: EngineBundle, *, backend: str = "dfa_ref",
-                 block_n: int = 256, interpret: bool = True):
+                 block_n: int = 256):
         if backend not in FUSED_BACKENDS:
             raise ValueError(f"backend {backend!r} has no fused dispatch "
                              f"(supported: {FUSED_BACKENDS})")
+        check_lane(backend)
         self.bundle = bundle
         self.backend = backend
         self.block_n = block_n
-        self.interpret = interpret
         self.words = bundle.words
         self._kernel = {"dfa": "pallas", "dfa_ref": "ref",
                         "parallel": "parallel"}[backend]
@@ -318,16 +345,8 @@ class FusedMatcher:
             luts[i] = e.byte_classes
             deltas[i, :e.bucket, :e.n_classes] = e.delta
             emits[i, :e.bucket] = e.emit
-        eng_idx = tuple(eng_idx)
-        if self._kernel == "pallas" and eng_idx != tuple(range(E)):
-            # pallas on jax 0.4.x can't route the slot->row indirection
-            # through BlockSpec index maps; expand shared tables ONCE here
-            # (host-side, per plan) rather than per dispatch on device
-            idx = list(eng_idx)
-            luts, deltas, emits = luts[idx], deltas[idx], emits[idx]
-            eng_idx = tuple(range(len(idx)))
         return _FusedPlan(cols=tuple(c for c, _ in pairs),
-                          eng_idx=eng_idx,
+                          eng_idx=tuple(eng_idx),
                           luts=jnp.asarray(luts), deltas=jnp.asarray(deltas),
                           emits=jnp.asarray(emits))
 
@@ -350,8 +369,7 @@ class FusedMatcher:
             bm, mask = dfa_scan_fused(data, plan.luts, plan.deltas,
                                       plan.emits, eng_idx=plan.eng_idx,
                                       backend=self._kernel,
-                                      block_n=self.block_n,
-                                      interpret=self.interpret)
+                                      block_n=self.block_n)
         _DISPATCH.inc()
         _MATCH_RECORDS.inc(int(n))
         return MatchResult(bm, mask)

@@ -164,7 +164,7 @@ class QueryEngine:
     def __init__(self, store: SegmentStore, *, mapper=None, profiler=None,
                  workers: int = 1, backend: str = "ref",
                  scan_backend: str = None, block_n: int = 1024,
-                 interpret: bool = True, arrangements: ArrangementStore = None,
+                 arrangements: ArrangementStore = None,
                  device_counts="auto", shards: int = 1,
                  worker_id: str = "query-0", shard_deadline_s: float = None,
                  shard_affinity: str = "weighted", prefetch: bool = True):
@@ -182,7 +182,7 @@ class QueryEngine:
             self.arrangements.set_prefetch_source(self._prefetch_item)
         self.plan_executor = PlanExecutor(
             backend=backend, scan_backend=scan_backend, block_n=block_n,
-            interpret=interpret, workers=workers,
+            workers=workers,
             arrangements=self.arrangements, device_counts=device_counts)
         self.executor = (ShardedQueryExecutor(self.plan_executor,
                                               shards=shards,

@@ -133,15 +133,14 @@ class PlanExecutor:
     MAX_SNAPSHOT_RETRIES = 3
 
     def __init__(self, *, backend: str = "ref", scan_backend: str = None,
-                 block_n: int = 1024, interpret: bool = True,
-                 workers: int = 1, arrangements: ArrangementStore = None,
+                 block_n: int = 1024, workers: int = 1,
+                 arrangements: ArrangementStore = None,
                  device_counts="auto"):
         if backend not in ("numpy", "ref", "pallas"):
             raise ValueError(f"unknown executor backend {backend!r}")
         self.backend = backend
         self.scan_backend = scan_backend
         self.block_n = block_n
-        self.interpret = interpret
         self.workers = workers
         self.arrangements = arrangements or ArrangementStore()
         self.device_counts = device_counts
@@ -231,8 +230,7 @@ class PlanExecutor:
                 match_dev, counts_dev = bitmap_query_words(
                     arr.stack, bits, arr.row_seg, num_segments=len(tasks),
                     backend="pallas" if self.backend == "pallas" else "ref",
-                    block_n=self.block_n, interpret=self.interpret,
-                    with_counts=with_counts)
+                    block_n=self.block_n, with_counts=with_counts)
             _STACKED_DISPATCH.inc()
             # the ONE counted D2H per query: on accelerators the
             # device-side segment_sum shrinks it from N bytes to S ints;
@@ -339,8 +337,7 @@ class PlanExecutor:
         if fused is None:
             bundle = self._scan_bundle(query)
             fused = FusedMatcher(bundle, backend=self.scan_backend,
-                                 block_n=self.block_n,
-                                 interpret=self.interpret)
+                                 block_n=self.block_n)
             with self._scan_lock:
                 if len(self._scan_fused) > 64:
                     self._scan_fused.clear()
@@ -460,8 +457,7 @@ class PlanExecutor:
         if matchers is None:
             matchers = build_matchers(self._scan_bundle(query),
                                       backend=self.scan_backend,
-                                      block_n=self.block_n,
-                                      interpret=self.interpret)
+                                      block_n=self.block_n)
             with self._scan_lock:
                 if len(self._scan_engines) > 64:    # bound growth: ad-hoc
                     self._scan_engines.clear()      # query shapes are open

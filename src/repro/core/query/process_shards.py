@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core import telemetry
 from repro.core.maintenance.lease import shard_of
+from repro.core.maintenance.process_pool import device_refusal
 
 _SHARD_DEATHS = telemetry.counter(
     "fluxsieve_query_shard_process_deaths_total",
@@ -51,8 +52,7 @@ def _shard_main(cfg: dict, conn) -> None:
     store.segments = [s for s in store.segments
                       if shard_of(s.segment_id, shards) == index]
     engine = QueryEngine(store, mapper=QueryMapper(cfg["ruleset"]),
-                         backend=cfg["backend"], block_n=cfg["block_n"],
-                         interpret=cfg["interpret"])
+                         backend=cfg["backend"], block_n=cfg["block_n"])
     ident = f"{cfg['worker_id']}/shard-{index}"
 
     while True:
@@ -162,18 +162,22 @@ class ProcessQueryPool:
 
     def __init__(self, root, ruleset, *, shards: int = 2,
                  backend: str = "ref", block_n: int = 1024,
-                 interpret: bool = True, segment_size: int = 100_000,
+                 segment_size: int = 100_000,
                  index_fields: tuple = (), worker_id: str = "query-proc",
                  recv_timeout: float = 120.0):
         if shards < 1:
             raise ValueError(f"shards must be >= 1, got {shards}")
+        refusal = device_refusal("ProcessQueryPool",
+                                 "QueryEngine(shards=N) (thread shards)")
+        if refusal:
+            raise RuntimeError(refusal)
         self.root = Path(root)
         self.shards = shards
         self.recv_timeout = float(recv_timeout)
         self._ctx = mp.get_context("spawn")
         self._cfg_base = {
             "root": str(self.root), "ruleset": ruleset, "backend": backend,
-            "block_n": block_n, "interpret": interpret,
+            "block_n": block_n,
             "segment_size": int(segment_size),
             "index_fields": tuple(index_fields),
             "num_shards": shards, "worker_id": worker_id,

@@ -257,7 +257,8 @@ def derive_enrichment_meta(bm: np.ndarray) -> tuple:
       * ``rule_counts``      — per-rule match counts (metadata-only counts);
       * posting lists for selective rules (the bitmap's inverted index).
     """
-    bm = np.asarray(bm)
+    # byte views need C order; a TPU may hand bitmaps back in F order
+    bm = np.ascontiguousarray(bm)
     bm_any = np.bitwise_or.reduce(bm, axis=0) if len(bm) else \
         np.zeros(bm.shape[1], np.uint32)
     meta = {"rule_bitmap_any": bm_any.tolist()}

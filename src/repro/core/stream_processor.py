@@ -108,7 +108,7 @@ class StreamProcessor:
     def __init__(self, bundle: EngineBundle, *, instance_id: str = "proc-0",
                  mode: str = "enrich", backend: str = "dfa_ref",
                  bus: ControlBus = None, store: ObjectStore = None,
-                 block_n: int = 256, interpret: bool = True,
+                 block_n: int = 256,
                  confirm_backend: str = "ref", retry_limit: int = 2,
                  retry_backoff_s: float = 0.002,
                  breaker: CircuitBreaker = None):
@@ -118,7 +118,6 @@ class StreamProcessor:
         self.mode = mode
         self.backend = backend
         self.block_n = block_n
-        self.interpret = interpret
         self.confirm_backend = confirm_backend   # dfa_selective pass 2
         self.bus = bus
         self.store = store
@@ -211,7 +210,7 @@ class StreamProcessor:
                 if active.fallback is None:
                     active.fallback = FusedMatcher(
                         active.bundle, backend=FALLBACK_BACKEND,
-                        block_n=self.block_n, interpret=self.interpret)
+                        block_n=self.block_n)
         return active.fallback
 
     def finalize(self, pending: PendingBatch) -> RecordBatch:
@@ -327,13 +326,11 @@ class StreamProcessor:
     def _install(self, bundle: EngineBundle, version_id: int) -> None:
         matchers = build_matchers(bundle, backend=self.backend,
                                   block_n=self.block_n,
-                                  interpret=self.interpret,
                                   confirm_backend=self.confirm_backend)
         fused = None
         if self.backend in FUSED_BACKENDS:
             fused = FusedMatcher(bundle, backend=self.backend,
-                                 block_n=self.block_n,
-                                 interpret=self.interpret)
+                                 block_n=self.block_n)
         idents = (ruleset_idents(bundle.ruleset()) if bundle.ruleset_json
                   else {})
         self.version_rules[version_id] = idents

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the match and query planes.
+
+Every ``pallas_call`` here runs in the Pallas interpreter on the CPU
+backend (tests, CPU rehearsals) and is compiled by Mosaic everywhere else;
+there is no option to choose.
+"""
+import jax
+
+
+def interpret() -> bool:
+    """True when the default backend is the CPU, which has no Mosaic
+    compiler: Pallas kernels then run interpreted."""
+    return jax.default_backend() == "cpu"

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret
+
 BLOCK_N = 1024
 
 
@@ -21,7 +23,10 @@ def _kernel(bm_ref, q_ref, match_ref, count_ref):
     hit = (bm_ref[...] & q_ref[...]) != 0                       # (blk, W)
     any_hit = jnp.any(hit, axis=1)
     match_ref[...] = any_hit.astype(jnp.int32)
-    count_ref[0, 0] = jnp.sum(any_hit.astype(jnp.int32))
+    # the block count fills one lane-wide row (a (1, 1) block breaks the
+    # 8x128 tiling rule); the wrapper keeps lane 0
+    count_ref[...] = jnp.full(count_ref.shape,
+                              jnp.sum(any_hit.astype(jnp.int32)), jnp.int32)
 
 
 def _query_kernel(bm_ref, q_ref, match_ref):
@@ -36,9 +41,8 @@ def _query_kernel(bm_ref, q_ref, match_ref):
     match_ref[...] = ok.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def bitmap_query_kernel(bitmaps, masks, *, block_n: int = BLOCK_N,
-                        interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_n",))
+def bitmap_query_kernel(bitmaps, masks, *, block_n: int = BLOCK_N):
     """bitmaps: (N, W) uint32 (N % block_n == 0); masks: (P, W) uint32.
     Returns match (N,) int32 — 1 where the record satisfies EVERY mask
     (AND across predicates, any-bit within each).  One grid pass over the
@@ -57,7 +61,7 @@ def bitmap_query_kernel(bitmaps, masks, *, block_n: int = BLOCK_N,
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret(),
     )(bitmaps, masks)
 
 
@@ -66,9 +70,8 @@ def _word_query_kernel(cols_ref, bits_ref, match_ref):
     match_ref[...] = jnp.all(hit, axis=1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def bitmap_word_query_kernel(cols, bits, *, block_n: int = BLOCK_N,
-                             interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_n",))
+def bitmap_word_query_kernel(cols, bits, *, block_n: int = BLOCK_N):
     """cols: (N, P) uint32 pre-gathered bitmap word columns (N % block_n
     == 0); bits: (P,) uint32 single-word masks.  Returns match (N,) int32 —
     the word-sliced fast path of ``bitmap_query_kernel``: the executor
@@ -86,19 +89,18 @@ def bitmap_word_query_kernel(cols, bits, *, block_n: int = BLOCK_N,
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret(),
     )(cols, bits[None])
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def bitmap_filter_kernel(bitmaps, query, *, block_n: int = BLOCK_N,
-                         interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_n",))
+def bitmap_filter_kernel(bitmaps, query, *, block_n: int = BLOCK_N):
     """bitmaps: (N, W) uint32 (N % block_n == 0); query: (1, W) uint32.
     Returns (match (N,) int32, block_counts (N//block_n, 1) int32)."""
     N, W = bitmaps.shape
     assert N % block_n == 0
     grid = (N // block_n,)
-    return pl.pallas_call(
+    match, counts = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
@@ -107,11 +109,12 @@ def bitmap_filter_kernel(bitmaps, query, *, block_n: int = BLOCK_N,
         ],
         out_specs=[
             pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], 1, 128), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret(),
     )(bitmaps, query)
+    return match, counts[:, :, 0]

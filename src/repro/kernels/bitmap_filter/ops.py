@@ -32,27 +32,25 @@ def _round_up(x: int, m: int) -> int:
 
 
 def bitmap_match(bitmaps, query, *, backend: str = "ref",
-                 block_n: int = BLOCK_N, interpret: bool = True):
+                 block_n: int = BLOCK_N):
     """(N, W) & (W,) -> match (N,) bool."""
     N = bitmaps.shape[0]
     if backend == "pallas":
         n_pad = _round_up(max(N, 1), block_n)
         bm = jnp.pad(bitmaps, ((0, n_pad - N), (0, 0)))
-        match, _ = bitmap_filter_kernel(bm, query[None], block_n=block_n,
-                                        interpret=interpret)
+        match, _ = bitmap_filter_kernel(bm, query[None], block_n=block_n)
         return match[:N].astype(bool)
     return bitmap_filter_ref(bitmaps, query)
 
 
 def bitmap_count(bitmaps, query, *, backend: str = "ref",
-                 block_n: int = BLOCK_N, interpret: bool = True):
+                 block_n: int = BLOCK_N):
     """Aggregation (count) query — paper's Q3/Qx-with-count."""
     if backend == "pallas":
         N = bitmaps.shape[0]
         n_pad = _round_up(max(N, 1), block_n)
         bm = jnp.pad(bitmaps, ((0, n_pad - N), (0, 0)))
-        _, counts = bitmap_filter_kernel(bm, query[None], block_n=block_n,
-                                         interpret=interpret)
+        _, counts = bitmap_filter_kernel(bm, query[None], block_n=block_n)
         return counts.sum(dtype=jnp.int32)
     return bitmap_filter_ref(bitmaps, query).sum(dtype=jnp.int32)
 
@@ -85,13 +83,13 @@ def _seg_bucket(s: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "backend",
-                                             "block_n", "interpret"))
+                                             "block_n"))
 def _query_dispatch(bm, masks, row_seg, *, num_segments: int, backend: str,
-                    block_n: int, interpret: bool):
+                    block_n: int):
     TRACE_COUNTS[("bitmap_query", backend)] += 1
     if backend == "pallas":
-        match = bitmap_query_kernel(bm, masks, block_n=block_n,
-                                    interpret=interpret).astype(jnp.bool_)
+        match = bitmap_query_kernel(bm, masks,
+                                    block_n=block_n).astype(jnp.bool_)
     else:
         match = bitmap_query_ref(bm, masks)
     counts = jax.ops.segment_sum(match.astype(jnp.int32), row_seg,
@@ -100,8 +98,7 @@ def _query_dispatch(bm, masks, row_seg, *, num_segments: int, backend: str,
 
 
 def bitmap_query_stacked(bitmaps, masks, row_seg, *, num_segments: int,
-                         backend: str = "ref", block_n: int = BLOCK_N,
-                         interpret: bool = True):
+                         backend: str = "ref", block_n: int = BLOCK_N):
     """bitmaps: (N, W) uint32 — the bitmap-scan segments of one query
     concatenated on N (any N; rows bucket via ``bucket_n``); masks:
     (P, W) uint32 conjunctive predicate masks; row_seg: (N,) int32 mapping
@@ -122,19 +119,17 @@ def bitmap_query_stacked(bitmaps, masks, row_seg, *, num_segments: int,
         row_seg = jnp.pad(row_seg, (0, n_pad - N))
     return _query_dispatch(
         bitmaps, masks, row_seg, num_segments=_seg_bucket(num_segments),
-        backend=backend, block_n=block_n, interpret=interpret)
+        backend=backend, block_n=block_n)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "backend",
-                                             "block_n", "interpret",
-                                             "with_counts"))
+                                             "block_n", "with_counts"))
 def _word_query_dispatch(cols, bits, row_seg, *, num_segments: int,
-                         backend: str, block_n: int, interpret: bool,
-                         with_counts: bool):
+                         backend: str, block_n: int, with_counts: bool):
     TRACE_COUNTS[("bitmap_query_words", backend)] += 1
     if backend == "pallas":
-        match = bitmap_word_query_kernel(cols, bits, block_n=block_n,
-                                         interpret=interpret).astype(jnp.bool_)
+        match = bitmap_word_query_kernel(cols, bits,
+                                         block_n=block_n).astype(jnp.bool_)
     else:
         match = bitmap_word_query_ref(cols, bits)
     if not with_counts:
@@ -149,7 +144,7 @@ def _word_query_dispatch(cols, bits, row_seg, *, num_segments: int,
 
 def bitmap_query_words(cols, bits, row_seg, *, num_segments: int,
                        backend: str = "ref", block_n: int = BLOCK_N,
-                       interpret: bool = True, with_counts: bool = True):
+                       with_counts: bool = True):
     """Word-sliced variant of ``bitmap_query_stacked`` — the executor's hot
     path.  cols: (N, P) uint32, the P bitmap WORD columns the query's
     single-rule predicates actually touch, pre-gathered at stack-build
@@ -170,5 +165,4 @@ def bitmap_query_words(cols, bits, row_seg, *, num_segments: int,
         row_seg = jnp.pad(row_seg, (0, n_pad - N))
     return _word_query_dispatch(
         cols, bits, row_seg, num_segments=_seg_bucket(num_segments),
-        backend=backend, block_n=block_n, interpret=interpret,
-        with_counts=with_counts)
+        backend=backend, block_n=block_n, with_counts=with_counts)

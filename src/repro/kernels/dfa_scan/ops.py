@@ -52,15 +52,14 @@ def _pad_rows(data, n_pad: int):
     return jnp.pad(data, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("eng_idx", "backend", "block_n",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("eng_idx", "backend", "block_n"))
 def _dispatch_fused(data, luts, deltas, emits, *, eng_idx: tuple,
-                    backend: str, block_n: int, interpret: bool):
+                    backend: str, block_n: int):
     TRACE_COUNTS[("dfa_scan", backend)] += 1
     if backend == "pallas":
         bm = dfa_scan_fused_kernel(data, luts, deltas, emits,
-                                   eng_idx=eng_idx, block_n=block_n,
-                                   interpret=interpret)
+                                   jnp.asarray(eng_idx, jnp.int32),
+                                   block_n=block_n)
         return bm, (bm != 0).any(axis=1)
     if backend == "ref":
         bms = dfa_scan_fused_ref(data, luts, deltas, emits, eng_idx=eng_idx)
@@ -79,8 +78,7 @@ def _dispatch_fused(data, luts, deltas, emits, *, eng_idx: tuple,
 
 
 def dfa_scan_fused(data, luts, deltas, emits, *, eng_idx: tuple = None,
-                   backend: str = "ref", block_n: int = BLOCK_N,
-                   interpret: bool = True):
+                   backend: str = "ref", block_n: int = BLOCK_N):
     """data: (F, N, L) uint8 (any N); luts: (E, 256) int32; deltas:
     (E, S, C) int32; emits: (E, S, W) uint32; eng_idx: length-F tuple
     mapping each field slot to its table row (default identity — engines
@@ -93,16 +91,15 @@ def dfa_scan_fused(data, luts, deltas, emits, *, eng_idx: tuple = None,
     data = _pad_rows(data, bucket_n(N, block_n))
     bm, mask = _dispatch_fused(data, luts, deltas, emits,
                                eng_idx=tuple(eng_idx), backend=backend,
-                               block_n=block_n, interpret=interpret)
+                               block_n=block_n)
     return bm[:N], mask[:N]
 
 
 def dfa_scan(data, delta, emit, byte_classes, *, backend: str = "ref",
-             block_n: int = BLOCK_N, interpret: bool = True):
+             block_n: int = BLOCK_N):
     """data: (N, L) uint8 (any N) -> (N, W) uint32 rule bitmaps."""
     bm, _ = dfa_scan_fused(data[None], byte_classes[None], delta[None],
-                           emit[None], backend=backend, block_n=block_n,
-                           interpret=interpret)
+                           emit[None], backend=backend, block_n=block_n)
     return bm
 
 
@@ -146,14 +143,13 @@ def _any_scan(cls, delta2_flat, n_classes):
 
 
 def dfa_scan_selective(data, delta, emit, byte_classes, delta2=None, *,
-                       backend: str = "ref", block_n: int = BLOCK_N,
-                       interpret: bool = True):
+                       backend: str = "ref", block_n: int = BLOCK_N):
     """Two-pass matcher: any-accept prefilter + full confirm on matches.
     data: (N, L) uint8 -> (N, W) uint32 (numpy).  Not jittable end-to-end
     (the confirm subset is data-dependent); both passes bucket their batch
-    dimension so neither retraces as N varies.  ``backend``/``block_n``/
-    ``interpret`` select the confirm-pass engine (threaded through from the
-    configuring MatchEngine rather than hardcoding the jnp oracle)."""
+    dimension so neither retraces as N varies.  ``backend``/``block_n``
+    select the confirm-pass engine (threaded through from the configuring
+    MatchEngine rather than hardcoding the jnp oracle)."""
     import numpy as onp
     if delta2 is None:
         delta2 = pack_delta_any(delta, emit)
@@ -171,7 +167,7 @@ def dfa_scan_selective(data, delta, emit, byte_classes, delta2=None, *,
         return out
     sub = onp.asarray(data)[idx]              # confirm pass buckets internally
     bm = dfa_scan(sub, delta, emit, byte_classes, backend=backend,
-                  block_n=block_n, interpret=interpret)
+                  block_n=block_n)
     out[idx] = onp.asarray(bm)
     return out
 
@@ -200,11 +196,4 @@ def _parallel_dfa(cls, delta, emit):
     prefix = jax.lax.associative_scan(compose, funcs, axis=1)   # (N, L, S)
     states = prefix[..., 0]                                     # start state 0
     bms = jnp.take(emit, states, axis=0)                        # (N, L, W)
-    return jax.lax.reduce_or(bms, axes=(1,)) if hasattr(jax.lax, "reduce_or") \
-        else _or_reduce(bms)
-
-
-def _or_reduce(x):
-    def f(a, b):
-        return a | b
-    return jax.lax.reduce(x, jnp.zeros((), x.dtype), f, (1,))
+    return jax.lax.reduce_or(bms, axes=(1,))

@@ -98,7 +98,7 @@ def _match_words_to_bitmap(M, lit_word, lit_bit, lit_rule, *, num_rules: int):
 
 
 def shift_or_match(data, tables: ShiftOrTables, *, backend: str = "ref",
-                   block_n: int = BLOCK_N, interpret: bool = True):
+                   block_n: int = BLOCK_N):
     """data: (N, L) uint8 -> (N, W) uint32 rule bitmaps."""
     N = data.shape[0]
     tbl = jnp.asarray(tables.tbl)
@@ -107,8 +107,7 @@ def shift_or_match(data, tables: ShiftOrTables, *, backend: str = "ref",
     if backend == "pallas":
         n_pad = _round_up(max(N, 1), block_n)
         d = jnp.pad(data, ((0, n_pad - N), (0, 0))).astype(jnp.int32)
-        M = shift_or_kernel(d, tbl, I[None], F[None], block_n=block_n,
-                            interpret=interpret)[:N]
+        M = shift_or_kernel(d, tbl, I[None], F[None], block_n=block_n)[:N]
     else:
         M = shift_or_ref(data, tbl, I, F)
     return _match_words_to_bitmap(

@@ -9,6 +9,11 @@ short keyword rules (DESIGN.md §2).
 VMEM per grid step: bytes tile 256x512 = 128 KiB (uint8->int32 widened
 outside), table 256 x Wb x 4 B (Wb=320 for 1000 short patterns ~ 320 KiB),
 states 2 x 256 x Wb x 4 B.
+
+Mosaic does not lower this kernel for TPU: the per-byte row gather
+``tbl[byte]`` spans 32 vregs along the gathered axis, and Mosaic gathers
+within one.  ``tests/test_tpu_compile.py`` records the refusal;
+``core.matcher`` refuses the ``shift_or`` lane on a compiled backend.
 """
 from __future__ import annotations
 
@@ -17,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret
 
 BLOCK_N = 256
 
@@ -41,9 +48,9 @@ def _kernel(data_ref, tbl_ref, init_ref, final_ref, out_ref):
     out_ref[...] = M
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n",))
 def shift_or_kernel(data, tbl, init_mask, final_mask, *,
-                    block_n: int = BLOCK_N, interpret: bool = True):
+                    block_n: int = BLOCK_N):
     """data: (N, L) int32 byte values; tbl: (256, Wb) uint32;
     init_mask/final_mask: (1, Wb) uint32 -> (N, Wb) uint32 match words."""
     N, L = data.shape
@@ -60,5 +67,5 @@ def shift_or_kernel(data, tbl, init_mask, final_mask, *,
         ],
         out_specs=pl.BlockSpec((block_n, Wb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, Wb), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret(),
     )(data, tbl, init_mask, final_mask)

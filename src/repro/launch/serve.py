@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.matcher import compile_bundle
 from repro.core.patterns import Rule, RuleSet
 from repro.core.query.engine import Query, QueryEngine
@@ -164,6 +165,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.port is not None:
         return _serve_frontend(args)

@@ -117,14 +117,10 @@ def test_shared_star_engine_deduped(backend):
     bm, _ = fused.match_batch(batch.columns, batch.text_fields,
                               len(batch)).to_host()
     plan = fused._plan(batch.text_fields)
-    if backend == "dfa":
-        # pallas can't take the slot->row indirection in its index maps:
-        # tables are expanded once at plan build, eng_idx is identity
-        assert plan.eng_idx == tuple(range(len(FIELDS)))
-        assert plan.deltas.shape[0] == len(FIELDS)
-    else:
-        assert plan.eng_idx == (0,) * len(FIELDS)  # one table, three slots
-        assert plan.deltas.shape[0] == 1
+    # both lanes keep one table for three slots (the Pallas kernel takes the
+    # slot->row map as a scalar-prefetch operand of its BlockSpecs)
+    assert plan.eng_idx == (0,) * len(FIELDS)
+    assert plan.deltas.shape[0] == 1
     np.testing.assert_array_equal(bm, oracle_bitmap(bundle, batch))
 
 
