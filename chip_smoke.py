@@ -1,15 +1,17 @@
 """Drive FluxSieve's main path once on one TPU chip and check its answers.
 
-    python chip_smoke.py [--records N] [--seed S]
+    python chip_smoke.py [--records N]
 
 Phases, all in this one process (a chip belongs to one process):
 
   ingest       generate ``--records`` log records (2 content fields of 512
-               bytes) from the seed, match them against 1000 rules on the
-               ``dfa_ref`` lane, and seal them into a spilled segment store
-               in segments of 50,000, with the ingest WAL on;
+               bytes) from the generator's seed, match them against 1000
+               rules on the ``dfa_ref`` lane, and seal them into a spilled
+               segment store in segments of 50,000, with the ingest WAL on
+               and the text index ``launch/serve.py --port`` builds;
   maintenance  activate the rule held back at ingest, backfill the sealed
-               segments with thread workers, compact;
+               segments with thread workers, compact (full segments leave
+               nothing to merge);
   query        Q1-Q4 in count and ids mode through ``QueryEngine`` with the
                ``ref`` and ``pallas`` bitmap lanes, each checked against
                the numpy full-scan oracle and the generator's truth;
@@ -195,7 +197,6 @@ def main(argv=None) -> int:
                                  formatter_class=argparse.
                                  RawDescriptionHelpFormatter)
     ap.add_argument("--records", type=int, default=1_000_000)
-    ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
     compile_cache.enable()
 
@@ -215,12 +216,12 @@ def main(argv=None) -> int:
     try:
         with tempfile.TemporaryDirectory(prefix="chip-smoke-") as tmp:
             phase = clock.start("ingest")
-            spec = WorkloadSpec(num_records=args.records, seed=args.seed)
-            world = build_world(records=args.records,
-                                ruleset=build_ruleset(spec, 1000),
+            spec = WorkloadSpec(num_records=args.records)
+            world = build_world(spec, ruleset=build_ruleset(spec, 1000),
                                 store=Path(tmp) / "store", wal=True,
                                 segment_size=50_000, batch_size=4096,
-                                hold_back_late_rule=True, seed=args.seed)
+                                hold_back_late_rule=True,
+                                index_fields=spec.content_fields)
             print(f"ingest: {world.times.records} records, "
                   f"{world.full_ruleset.num_rules} rules "
                   f"({world.ruleset.num_rules} active), "

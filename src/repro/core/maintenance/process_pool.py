@@ -48,6 +48,7 @@ from repro.core import telemetry
 from repro.core.control_plane import CONTROL_DIRNAME, DurableControlBus
 from repro.core.maintenance.backfill import BackfillReport, merge_reports
 from repro.core.maintenance.lease import DurableLeaseManager
+from repro.device import on_cpu
 
 _DEATHS = telemetry.counter(
     "fluxsieve_maintenance_worker_deaths_total",
@@ -56,19 +57,6 @@ _DEATHS = telemetry.counter(
 _RESPAWNS = telemetry.counter(
     "fluxsieve_maintenance_worker_respawns_total",
     help="Maintenance worker processes respawned under their old identity.")
-
-
-def device_refusal(what: str, instead: str):
-    """-> why ``what``, whose child processes build JAX matchers or
-    executors, cannot run here; None on the CPU backend.  A chip belongs to
-    one process, and this one has touched JAX."""
-    import jax
-    backend = jax.default_backend()
-    if backend == "cpu":
-        return None
-    return (f"{what} cannot run on the {backend!r} backend: its worker "
-            f"processes would need the device this process holds; use "
-            f"{instead}")
 
 
 def _worker_main(cfg: dict, conn) -> None:
@@ -182,10 +170,12 @@ class ProcessMaintenancePool:
                  recv_timeout: float = 120.0, respawn: bool = True):
         if num_workers < 1:
             raise ValueError(f"num_workers must be >= 1, got {num_workers}")
-        refusal = device_refusal("ProcessMaintenancePool",
-                                 "MaintenanceWorkerPool (thread workers)")
-        if refusal:
-            raise RuntimeError(refusal)
+        if not on_cpu():
+            raise RuntimeError(
+                "ProcessMaintenancePool runs on the CPU backend only: its "
+                "worker processes build matchers and would need the device "
+                "this process holds; use MaintenanceWorkerPool (thread "
+                "workers)")
         self.root = Path(root)
         self.store = store
         if objects_root is None:

@@ -42,7 +42,7 @@ import numpy as np
 from repro.core import telemetry
 from repro.core.automaton import CompiledEngine, compile_rules, words_for_rules
 from repro.core.patterns import RuleSet
-from repro.kernels import interpret
+from repro.device import on_cpu
 from repro.kernels.dfa_scan.ops import (dfa_scan, dfa_scan_fused,
                                         dfa_scan_selective, pack_delta_any)
 from repro.kernels.shift_or import ops as shift_or_ops
@@ -68,7 +68,7 @@ def check_lane(backend: str) -> None:
     """Raise when ``backend`` needs a Pallas kernel that the current
     backend cannot compile (everything but the CPU interpreter, for the
     lanes in ``TPU_REFUSED``)."""
-    if backend in TPU_REFUSED and not interpret():
+    if backend in TPU_REFUSED and not on_cpu():
         raise NotImplementedError(
             f"match backend {backend!r} cannot run on "
             f"{jax.default_backend()!r}: {TPU_REFUSED[backend]}. "

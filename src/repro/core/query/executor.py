@@ -56,6 +56,7 @@ from repro.core.query.arrangement import ArrangementItem, ArrangementStore
 from repro.core.query.planner import (BITMAP, FALLBACK, FULL_SCAN,
                                       META_COUNT, POSTINGS, PRUNED,
                                       TEXT_INDEX)
+from repro.device import on_cpu
 
 # -- device->host accounting -------------------------------------------------
 # The batched bitmap path performs exactly ONE D2H transfer per query; tests
@@ -190,8 +191,7 @@ class PlanExecutor:
     # -- stacked bitmap class (single device dispatch, single D2H) -----------
     def _use_device_counts(self) -> bool:
         if self.device_counts == "auto":
-            import jax
-            self.device_counts = jax.default_backend() not in ("cpu",)
+            self.device_counts = not on_cpu()
         return bool(self.device_counts)
 
     def _run_stacked(self, plan, tasks, cache: bool, owner: str) -> list:

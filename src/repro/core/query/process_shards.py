@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.core import telemetry
 from repro.core.maintenance.lease import shard_of
-from repro.core.maintenance.process_pool import device_refusal
+from repro.device import on_cpu
 
 _SHARD_DEATHS = telemetry.counter(
     "fluxsieve_query_shard_process_deaths_total",
@@ -167,10 +167,12 @@ class ProcessQueryPool:
                  recv_timeout: float = 120.0):
         if shards < 1:
             raise ValueError(f"shards must be >= 1, got {shards}")
-        refusal = device_refusal("ProcessQueryPool",
-                                 "QueryEngine(shards=N) (thread shards)")
-        if refusal:
-            raise RuntimeError(refusal)
+        if not on_cpu():
+            raise RuntimeError(
+                "ProcessQueryPool runs on the CPU backend only: its shard "
+                "processes build query executors and would need the device "
+                "this process holds; use QueryEngine(shards=N) (thread "
+                "shards)")
         self.root = Path(root)
         self.shards = shards
         self.recv_timeout = float(recv_timeout)

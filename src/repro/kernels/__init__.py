@@ -1,13 +1,6 @@
 """Pallas kernels of the match and query planes.
 
 Every ``pallas_call`` here runs in the Pallas interpreter on the CPU
-backend (tests, CPU rehearsals) and is compiled by Mosaic everywhere else;
-there is no option to choose.
+backend (tests, CPU rehearsals) and is compiled by Mosaic everywhere else
+(``repro.device.on_cpu``); there is no option to choose.
 """
-import jax
-
-
-def interpret() -> bool:
-    """True when the default backend is the CPU, which has no Mosaic
-    compiler: Pallas kernels then run interpreted."""
-    return jax.default_backend() == "cpu"

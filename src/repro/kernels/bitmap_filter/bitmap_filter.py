@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import interpret
+from repro.device import on_cpu
 
 BLOCK_N = 1024
 
@@ -61,7 +61,7 @@ def bitmap_query_kernel(bitmaps, masks, *, block_n: int = BLOCK_N):
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret(),
+        interpret=on_cpu(),
     )(bitmaps, masks)
 
 
@@ -89,7 +89,7 @@ def bitmap_word_query_kernel(cols, bits, *, block_n: int = BLOCK_N):
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N,), jnp.int32),
-        interpret=interpret(),
+        interpret=on_cpu(),
     )(cols, bits[None])
 
 
@@ -115,6 +115,6 @@ def bitmap_filter_kernel(bitmaps, query, *, block_n: int = BLOCK_N):
             jax.ShapeDtypeStruct((N,), jnp.int32),
             jax.ShapeDtypeStruct((grid[0], 1, 128), jnp.int32),
         ],
-        interpret=interpret(),
+        interpret=on_cpu(),
     )(bitmaps, query)
     return match, counts[:, :, 0]

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import interpret
+from repro.device import on_cpu
 
 BLOCK_N = 256
 LANES = 128       # byte positions per aligned input tile
@@ -121,5 +121,5 @@ def dfa_scan_fused_kernel(data, luts, deltas, emits, eng_idx, *,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, W), jnp.uint32),
-        interpret=interpret(),
+        interpret=on_cpu(),
     )(eng_idx, data, luts.reshape(E, 1, 256), deltas, emits)

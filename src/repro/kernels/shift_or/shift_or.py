@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import interpret
+from repro.device import on_cpu
 
 BLOCK_N = 256
 
@@ -67,5 +67,5 @@ def shift_or_kernel(data, tbl, init_mask, final_mask, *,
         ],
         out_specs=pl.BlockSpec((block_n, Wb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, Wb), jnp.uint32),
-        interpret=interpret(),
+        interpret=on_cpu(),
     )(data, tbl, init_mask, final_mask)

@@ -14,8 +14,6 @@ import time
 from dataclasses import dataclass
 from pathlib import Path
 
-import numpy as np
-
 from repro import compile_cache
 from repro.core import telemetry
 from repro.core.control_plane import (CONTROL_DIRNAME, ControlBus,
@@ -25,7 +23,6 @@ from repro.core.maintenance import (Compactor, MaintenancePolicy,
                                     MaintenanceWorkerPool,
                                     ProcessMaintenancePool, RetentionPolicy,
                                     RetentionWorker, SpillGC)
-from repro.core.maintenance.process_pool import device_refusal
 from repro.core.matcher import compile_bundle
 from repro.core.object_store import ObjectStore
 from repro.core.patterns import Rule, RuleSet
@@ -38,6 +35,7 @@ from repro.core.stream_processor import StreamProcessor
 from repro.core.updater import MatcherUpdater
 from repro.data.generator import LogGenerator, WorkloadSpec
 from repro.data.pipeline import IngestPipeline
+from repro.device import on_cpu
 
 
 def synth_ruleset(spec: WorkloadSpec, num_rules: int) -> RuleSet:
@@ -70,21 +68,20 @@ class World:
     engine: QueryEngine
 
 
-def build_world(*, records: int, rules: int = 1000, fields: int = 2,
+def build_world(spec: WorkloadSpec, *, rules: int = 1000,
                 mode: str = "enrich", backend: str = "dfa_ref",
                 store=None, wal: bool = False, segment_size: int = 50_000,
                 batch_size: int = 4096, hold_back_late_rule: bool = False,
                 durable_control: bool = False, ruleset: RuleSet = None,
-                seed: int = 7) -> World:
-    """Generate ``records`` log records, match and enrich them through the
+                index_fields: tuple = (), shards: int = 1) -> World:
+    """Generate ``spec``'s log records, match and enrich them through the
     stream processor, and seal them into a segment store (spilled under
-    ``store`` when given).  ``ruleset`` defaults to ``synth_ruleset``;
-    ``hold_back_late_rule`` leaves the last planted rule out of the ingest
-    engine so sealed segments need a backfill once it activates.
-    ``durable_control`` puts the bus and the artifact store under ``store``
-    for worker processes.  ``seed`` seeds the generator."""
-    spec = WorkloadSpec(num_records=records, num_content_fields=fields,
-                        seed=seed)
+    ``store`` when given, with a text index on ``index_fields``).
+    ``ruleset`` defaults to ``synth_ruleset``; ``hold_back_late_rule``
+    leaves the last planted rule out of the ingest engine so sealed
+    segments need a backfill once it activates.  ``durable_control`` puts
+    the bus and the artifact store under ``store`` for worker processes.
+    ``shards`` is the query engine's thread-shard width."""
     gen = LogGenerator(spec)
     full_ruleset = ruleset or synth_ruleset(spec, rules)
     late_rule = None
@@ -114,15 +111,18 @@ def build_world(*, records: int, rules: int = 1000, fields: int = 2,
         # restart over a populated root: reopen the committed store (a
         # fresh SegmentStore here would disown every durable segment on
         # its first manifest commit)
-        seg_store = SegmentStore.load(root, segment_size=segment_size)
+        seg_store = SegmentStore.load(root, segment_size=segment_size,
+                                      index_fields=index_fields)
     else:
-        seg_store = SegmentStore(segment_size=segment_size, root=store)
+        seg_store = SegmentStore(segment_size=segment_size, root=store,
+                                 index_fields=index_fields)
     pipe = IngestPipeline(gen, seg_store, proc, wal=wal)
     start = pipe.recover() if wal else 0
     times = pipe.run(batch_size=batch_size, start=start)
     mapper = QueryMapper(active)
     profiler = QueryProfiler()
-    engine = QueryEngine(seg_store, mapper=mapper, profiler=profiler)
+    engine = QueryEngine(seg_store, mapper=mapper, profiler=profiler,
+                         shards=shards)
     return World(spec=spec, gen=gen, full_ruleset=full_ruleset,
                  ruleset=active, late_rule=late_rule, bus=bus,
                  ostore=ostore, updater=updater, proc=proc, store=seg_store,
@@ -263,10 +263,11 @@ def main(argv=None) -> int:
             ap.error("--worker-model process needs --store (worker "
                      "processes coordinate through the durable bus/leases "
                      "under it)")
-        refusal = device_refusal("--worker-model process",
-                                 "--worker-model thread")
-        if refusal:
-            ap.error(refusal)
+        if not on_cpu():
+            ap.error("--worker-model process runs on the CPU backend only: "
+                     "its worker processes build matchers and would need "
+                     "the device this process holds; use --worker-model "
+                     "thread")
 
     stop_dumper = None
     if args.metrics_dump and args.metrics_interval:
@@ -279,8 +280,9 @@ def main(argv=None) -> int:
         threading.Thread(target=_periodic, daemon=True,
                          name="metrics-dumper").start()
 
-    world = build_world(records=args.records, rules=args.rules,
-                        fields=args.fields, mode=args.mode,
+    world = build_world(WorkloadSpec(num_records=args.records,
+                                     num_content_fields=args.fields),
+                        rules=args.rules, mode=args.mode,
                         backend=args.backend, store=args.store, wal=args.wal,
                         segment_size=args.segment_size,
                         batch_size=args.batch_size,

@@ -36,23 +36,18 @@ from repro.data.generator import LogGenerator, WorkloadSpec
 
 
 def _serve_frontend(args) -> int:
-    """Build a world (same construction as the benchmarks) and serve it."""
-    from repro.data.pipeline import IngestPipeline
-    from repro.launch.ingest import synth_ruleset
+    """Build a world (``launch.ingest.build_world``) and serve it."""
+    from repro.launch.ingest import build_world
     from repro.serve.frontend import FrontEnd
 
     spec = WorkloadSpec(num_records=args.records)
-    gen = LogGenerator(spec)
-    ruleset = synth_ruleset(spec, args.rules)
-    proc = StreamProcessor(compile_bundle(ruleset, spec.content_fields),
-                           backend="dfa_ref")
-    store = SegmentStore(segment_size=args.segment_size, root=args.store,
-                         index_fields=spec.content_fields)
-    times = IngestPipeline(gen, store, proc).run(batch_size=4096)
-    print(f"ingested {times.records} records into {len(store.segments)} "
-          f"segments ({times.throughput():,.0f} rec/s)")
-    engine = QueryEngine(store, mapper=QueryMapper(ruleset),
-                         shards=args.shards)
+    world = build_world(spec, rules=args.rules, store=args.store,
+                        segment_size=args.segment_size,
+                        index_fields=spec.content_fields, shards=args.shards)
+    proc, store, engine = world.proc, world.store, world.engine
+    print(f"ingested {world.times.records} records into "
+          f"{len(store.segments)} segments "
+          f"({world.times.throughput():,.0f} rec/s)")
 
     def ingest_sink(batch):
         out = proc.process(batch)
