@@ -143,11 +143,13 @@ class ArrangementLease:
     bug — it is released at finalization with a ``ResourceWarning`` naming
     the owning worker so leaks are attributable, not silent pins."""
 
-    __slots__ = ("arrangement", "owner", "_store", "_released", "__weakref__")
+    __slots__ = ("arrangement", "owner", "hit", "_store", "_released",
+                 "__weakref__")
 
     def __init__(self, arrangement: Arrangement, owner: str, store):
         self.arrangement = arrangement
         self.owner = owner
+        self.hit = False            # leased a live arrangement, no build
         self._store = store
         self._released = False
 
@@ -336,7 +338,9 @@ class ArrangementStore:
                     arr.refcount += 1
                     self.lease_hits += 1
                     _T_LEASE_HITS.inc()
-                    return self._make_lease_locked(arr, owner)
+                    lease = self._make_lease_locked(arr, owner)
+                    lease.hit = True
+                    return lease
                 ev = self._building.get(key)
                 if ev is None:
                     self._building[key] = ev = threading.Event()

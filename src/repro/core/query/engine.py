@@ -252,8 +252,10 @@ class QueryEngine:
         t0 = time.perf_counter()
         with telemetry.span("query/execute", cat="query",
                             mode=query.mode, query=query.name):
-            plan = self.planner.plan(query, list(self.store.segments),
-                                     path=path, flux=flux, cache=not cold)
+            with telemetry.span("query/plan", cat="query"):
+                plan = self.planner.plan(query, list(self.store.segments),
+                                         path=path, flux=flux,
+                                         cache=not cold)
             res = self._run(plan, cache=not cold)
         res.latency_s = time.perf_counter() - t0
         res.path = plan.path
@@ -293,7 +295,9 @@ class QueryEngine:
             if plan.query.mode == "copy" and len(ids):
                 matches.append((task.seg, ids))
         if plan.query.mode == "copy":
-            res.records = self._materialize(matches, cache, res)
+            with telemetry.span("query/materialize", cat="query",
+                                segments=len(matches)):
+                res.records = self._materialize(matches, cache, res)
         if res.segments_failed:
             _QUERY_PARTIAL.inc()
             telemetry.emit("query_partial", plane="query",

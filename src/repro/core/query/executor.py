@@ -209,16 +209,19 @@ class PlanExecutor:
             token=t.seg.meta_token(), num_records=int(t.seg.num_records),
             load=self._host_loader(t.seg, cache, st))
             for t, st in zip(tasks, stats)]
-        if cache:
-            lease = self.arrangements.lease(items, words,
-                                            block_n=self.block_n,
-                                            owner=owner)
-        else:       # cold run: private build, pays (and accounts) its I/O
-            lease = self.arrangements.build_ephemeral(
-                items, words, block_n=self.block_n, owner=owner)
+        with telemetry.span("query/arrangement", cat="query",
+                            segments=len(tasks)) as span:
+            bits = self._device_bits(plan.flux.rule_ids, bits_np)
+            if cache:
+                lease = self.arrangements.lease(items, words,
+                                                block_n=self.block_n,
+                                                owner=owner)
+            else:   # cold run: private build, pays (and accounts) its I/O
+                lease = self.arrangements.build_ephemeral(
+                    items, words, block_n=self.block_n, owner=owner)
+            span.set(hit=lease.hit)
         try:
             arr = lease.arrangement
-            bits = self._device_bits(plan.flux.rule_ids, bits_np)
             copy_mode = plan.query.mode == "copy"
             # retention straddlers need row ids (the engine filters them by
             # timestamp), so device-side count reduction is off for them
@@ -235,11 +238,13 @@ class PlanExecutor:
             # the ONE counted D2H per query: on accelerators the
             # device-side segment_sum shrinks it from N bytes to S ints;
             # on XLA CPU the mask transfer is the measured win
-            if with_counts:
-                counts = np.asarray(_to_host(counts_dev))[:len(tasks)]
-                match = None
-            else:
-                match = _to_host(match_dev)
+            with telemetry.span("query/device_wait", cat="query",
+                                counts=with_counts):
+                if with_counts:
+                    counts = np.asarray(_to_host(counts_dev))[:len(tasks)]
+                    match = None
+                else:
+                    match = _to_host(match_dev)
             lens = arr.lens
         finally:
             lease.release()

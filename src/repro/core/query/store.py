@@ -504,18 +504,20 @@ class Segment:
     def spill(self, root: Path) -> None:
         """Write one .npy per column (+ .fts.npz per indexed field)."""
         faults.fire("store.spill", segment=self.segment_id)
-        d = Path(root) / f"segment-{self.segment_id:06d}"
-        d.mkdir(parents=True, exist_ok=True)
-        for name, arr in self._columns.items():
-            np.save(d / f"{name}.npy", arr)
-        for fieldname, idx in self._text_index.items():
-            _save_index(d / f"{fieldname}.fts.npz", idx)
-        if self._rule_postings is not None:
-            _save_index(d / "rule_postings.npz", self._rule_postings)
-        (d / "meta.json").write_text(json.dumps(
-            {**self.meta, "segment_id": self.segment_id,
-             "num_records": self.num_records},
-            default=_json_np))
+        with telemetry.span("store/spill", cat="store",
+                            segment=int(self.segment_id)):
+            d = Path(root) / f"segment-{self.segment_id:06d}"
+            d.mkdir(parents=True, exist_ok=True)
+            for name, arr in self._columns.items():
+                np.save(d / f"{name}.npy", arr)
+            for fieldname, idx in self._text_index.items():
+                _save_index(d / f"{fieldname}.fts.npz", idx)
+            if self._rule_postings is not None:
+                _save_index(d / "rule_postings.npz", self._rule_postings)
+            (d / "meta.json").write_text(json.dumps(
+                {**self.meta, "segment_id": self.segment_id,
+                 "num_records": self.num_records},
+                default=_json_np))
         self.path = d
 
     def drop_caches(self) -> None:
@@ -736,17 +738,19 @@ class SegmentStore:
             self._publish_epoch((seg.segment_id,), "seal", added=(seg,))
 
     def _seal_locked(self, n: int) -> Segment:
-        merged = RecordBatch.concat(self._active)
-        head, tail = merged.slice(0, n), merged.slice(n, len(merged))
-        self._active = [tail] if len(tail) else []
-        self._active_count = len(tail)
-        # the watermark advances with the SAME manifest commit that
-        # registers the sealed segment (one atomic write): a crash can
-        # never observe a registered segment whose rows are not counted,
-        # or a watermark covering rows with no registered segment
-        self._sealed_rows += n
-        seg = self._make_segment(head, ingest_seal=True)
-        self.segments.append(seg)
+        with telemetry.span("store/seal", cat="store", rows=int(n)):
+            merged = RecordBatch.concat(self._active)
+            head, tail = merged.slice(0, n), merged.slice(n, len(merged))
+            self._active = [tail] if len(tail) else []
+            self._active_count = len(tail)
+            # the watermark advances with the SAME manifest commit that
+            # registers the sealed segment (one atomic write): a crash
+            # can never observe a registered segment whose rows are not
+            # counted, or a watermark covering rows with no registered
+            # segment
+            self._sealed_rows += n
+            seg = self._make_segment(head, ingest_seal=True)
+            self.segments.append(seg)
         return seg
 
     def _make_segment(self, batch: RecordBatch, register: bool = True,

@@ -23,7 +23,7 @@ from repro.core.telemetry.export import (merge_dumps, merge_snapshots,
                                          prometheus_text, snapshot,
                                          write_dump)
 from repro.core.telemetry.metrics import (counter, enabled, gauge, histogram,
-                                          set_enabled, set_exemplars)
+                                          set_enabled)
 from repro.core.telemetry.trace import export_chrome_trace, span
 
 
@@ -53,7 +53,7 @@ def suppressed(site: str, err: BaseException) -> None:
 
 __all__ = [
     "counter", "gauge", "histogram", "enabled", "set_enabled",
-    "set_exemplars", "span", "export_chrome_trace", "emit", "suppressed",
+    "span", "export_chrome_trace", "emit", "suppressed",
     "prometheus_text", "snapshot", "write_dump", "merge_dumps",
     "merge_snapshots", "reset", "metrics", "trace", "events", "export",
 ]

@@ -3,10 +3,7 @@
 ``prometheus_text()`` renders the whole registry in the text format every
 Prometheus-compatible scraper understands (`# HELP` / `# TYPE` headers,
 ``name{label="v"} value`` samples, histograms as cumulative ``_bucket{le=}``
-series plus ``_sum``/``_count``).  When exemplar capture is on
-(``metrics.set_exemplars(True)``) bucket lines carry OpenMetrics exemplar
-suffixes — ``... 42 # {span_id="1234"} 0.0371`` — linking a bucket to one
-trace span that landed in it.  ``snapshot()`` is the JSON-able dict the
+series plus ``_sum``/``_count``).  ``snapshot()`` is the JSON-able dict the
 benchmarks embed per suite; ``write_dump(dir, prefix=...)`` writes all
 three artifacts (``metrics.prom``, ``snapshot.json``, ``trace.json``) for
 offline inspection — the trace loads directly in https://ui.perfetto.dev.
@@ -45,22 +42,9 @@ def _labels_text(labels: dict, extra: dict = None) -> str:
     return "{" + body + "}"
 
 
-def _exemplar_text(exemplar) -> str:
-    """OpenMetrics exemplar suffix for a bucket line ('' when absent)."""
-    if not exemplar:
-        return ""
-    sid, value = exemplar
-    return f' # {{span_id="{int(sid)}"}} {float(value):.9g}'
-
-
-def prometheus_text(registry: "metrics.MetricsRegistry" = None, *,
-                    exemplars: bool = None) -> str:
-    """Render the registry in Prometheus text exposition format.
-    ``exemplars`` defaults to the global capture flag
-    (``metrics.exemplars_enabled()``)."""
+def prometheus_text(registry: "metrics.MetricsRegistry" = None) -> str:
+    """Render the registry in Prometheus text exposition format."""
     reg = registry if registry is not None else metrics.REGISTRY
-    if exemplars is None:
-        exemplars = metrics.exemplars_enabled()
     # group series under one HELP/TYPE header per metric name
     by_name = {}
     for kind, name, m in reg.collect():
@@ -80,16 +64,14 @@ def prometheus_text(registry: "metrics.MetricsRegistry" = None, *,
                 with m._lock:
                     counts = list(m._counts)
                     count, total = m._count, m._sum
-                    witnesses = list(m._exemplars)
                 for i, c in enumerate(counts):
                     if not c:
                         continue
                     cum += c
                     le = f"{m.bucket_bounds(i)[1]:.9g}"
-                    ex = (_exemplar_text(witnesses[i]) if exemplars else "")
                     lines.append(f"{name}_bucket"
                                  f"{_labels_text(m.labels, {'le': le})} "
-                                 f"{cum}{ex}")
+                                 f"{cum}")
                 lines.append(f"{name}_bucket"
                              f"{_labels_text(m.labels, {'le': '+Inf'})} "
                              f"{count}")
@@ -157,11 +139,6 @@ def _merge_series(kind: str, into: list, series: list) -> None:
             buckets[le] = buckets.get(le, 0) + c
         if buckets:
             acc["buckets"] = buckets
-        exemplars = dict(acc.get("exemplars") or {})
-        for le, e in (s.get("exemplars") or {}).items():
-            exemplars.setdefault(le, e)     # first witness per bucket wins
-        if exemplars:
-            acc["exemplars"] = exemplars
 
 
 def _requantile(acc: dict) -> None:
@@ -215,7 +192,7 @@ def merge_snapshots(snaps: list) -> dict:
     return merged
 
 
-def prometheus_from_snapshot(snap: dict, *, exemplars: bool = True) -> str:
+def prometheus_from_snapshot(snap: dict) -> str:
     """Render a (possibly merged) snapshot dict in Prometheus text format —
     same grammar ``scripts/check_prom_format.py`` validates for the live
     registry rendering."""
@@ -240,16 +217,11 @@ def prometheus_from_snapshot(snap: dict, *, exemplars: bool = True) -> str:
             count = s.get("count", 0)
             buckets = sorted(((float(le), le, c) for le, c
                               in (s.get("buckets") or {}).items()))
-            witnesses = s.get("exemplars") or {}
             cum = 0
             for _, le, c in buckets:
                 cum += c
-                ex = ""
-                if exemplars and le in witnesses:
-                    w = witnesses[le]
-                    ex = _exemplar_text((w["span_id"], w["value"]))
                 lines.append(f"{name}_bucket"
-                             f"{_labels_text(labels, {'le': le})} {cum}{ex}")
+                             f"{_labels_text(labels, {'le': le})} {cum}")
             lines.append(f"{name}_bucket"
                          f"{_labels_text(labels, {'le': '+Inf'})} {count}")
             lines.append(f"{name}_sum{_labels_text(labels)} "

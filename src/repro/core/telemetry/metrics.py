@@ -40,31 +40,6 @@ NUM_BUCKETS = LOG2_MAX - LOG2_MIN + 1   # bucket i covers [2^(MIN+i), 2^(MIN+i+1
 
 _ENABLED = True
 
-# Exemplar capture (off by default — one extra callable per observe when
-# on): each histogram bucket remembers ONE (span_id, value) witness, so a
-# slow p99 bucket links straight to the trace span that caused it.  The
-# source callable is registered by the tracer (``trace.current_span_id``)
-# to avoid a circular import; exports render OpenMetrics exemplar syntax.
-_EXEMPLARS = False
-_EXEMPLAR_SOURCE = None
-
-
-def set_exemplars(flag: bool) -> None:
-    """Enable/disable histogram exemplar capture (and rendering)."""
-    global _EXEMPLARS
-    _EXEMPLARS = bool(flag)
-
-
-def exemplars_enabled() -> bool:
-    return _EXEMPLARS
-
-
-def set_exemplar_source(fn) -> None:
-    """Register the zero-arg span-id source (the tracer installs its
-    ``current_span_id`` at import; 0/None means "no span open")."""
-    global _EXEMPLAR_SOURCE
-    _EXEMPLAR_SOURCE = fn
-
 
 def set_enabled(flag: bool) -> None:
     """Globally enable/disable telemetry mutation (spans and events consult
@@ -171,7 +146,7 @@ class Histogram:
     exact observed [min, max]."""
 
     __slots__ = ("name", "labels", "_counts", "_count", "_sum",
-                 "_min", "_max", "_exemplars", "_lock")
+                 "_min", "_max", "_lock")
 
     def __init__(self, name: str, labels: dict):
         self.name = name
@@ -181,9 +156,6 @@ class Histogram:
         self._sum = 0.0
         self._min = math.inf
         self._max = -math.inf
-        # one (span_id, value) witness per bucket, kept only while exemplar
-        # capture is on (None entries otherwise — zero steady-state cost)
-        self._exemplars = [None] * NUM_BUCKETS
         self._lock = threading.Lock()
 
     @staticmethod
@@ -203,11 +175,6 @@ class Histogram:
             return
         v = float(v)
         i = self.bucket_index(v)
-        exemplar = None
-        if _EXEMPLARS and _EXEMPLAR_SOURCE is not None:
-            sid = _EXEMPLAR_SOURCE()
-            if sid:
-                exemplar = (int(sid), v)
         with self._lock:
             self._counts[i] += 1
             self._count += 1
@@ -216,8 +183,6 @@ class Histogram:
                 self._min = v
             if v > self._max:
                 self._max = v
-            if exemplar is not None:
-                self._exemplars[i] = exemplar
 
     @property
     def count(self) -> int:
@@ -252,7 +217,6 @@ class Histogram:
             self._sum = 0.0
             self._min = math.inf
             self._max = -math.inf
-            self._exemplars = [None] * NUM_BUCKETS
 
     def _snapshot(self) -> dict:
         with self._lock:
@@ -260,7 +224,6 @@ class Histogram:
             count, total = self._count, self._sum
             mn = self._min if count else None
             mx = self._max if count else None
-            exemplars = list(self._exemplars)
         out = {"labels": self.labels, "count": count, "sum": total,
                "min": mn, "max": mx}
         if count:
@@ -269,12 +232,6 @@ class Histogram:
             out["p99"] = self.quantile(0.99)
             out["buckets"] = {f"{self.bucket_bounds(i)[1]:.9g}": c
                               for i, c in enumerate(counts) if c}
-            ex = {f"{self.bucket_bounds(i)[1]:.9g}":
-                  {"span_id": e[0], "value": e[1]}
-                  for i, e in enumerate(exemplars)
-                  if e is not None and counts[i]}
-            if ex:
-                out["exemplars"] = ex
         return out
 
 

@@ -12,6 +12,8 @@ maintenance backfill cycles all land on ONE timeline, one track per thread.
   * parent/child linkage rides a thread-local stack: each finished span
     records its parent's id in ``args.parent`` (the Chrome viewer already
     nests same-thread spans by ts/dur; the explicit id survives export);
+  * ``span.set(**args)`` adds arguments known only inside the region (an
+    outcome, a cache hit) to the span's exported ``args``;
   * ``export_chrome_trace()`` -> the trace-event JSON object; timestamps
     are microseconds since tracer start, durations microseconds, as the
     format requires.
@@ -54,6 +56,10 @@ class _Span:
         stack.append(self.span_id)
         return self
 
+    def set(self, **args) -> None:
+        """Add ``args`` (JSON-able scalars) to the span's arguments."""
+        self.args.update(args)
+
     def __exit__(self, *exc) -> None:
         t = self.tracer
         t1 = t._clock()
@@ -83,6 +89,9 @@ class _NullSpan:
 
     def __enter__(self):
         return self
+
+    def set(self, **args) -> None:
+        return None
 
     def __exit__(self, *exc):
         return None
@@ -163,19 +172,6 @@ class Tracer:
 
 # -- the process-wide default tracer -----------------------------------------
 TRACER = Tracer()
-
-
-def current_span_id() -> int:
-    """Innermost open span's id on THIS thread (0 when none) — the
-    histogram exemplar source: a latency observed inside a span links the
-    bucket back to the exact span that produced it."""
-    st = TRACER._stack()
-    return st[-1] if st else 0
-
-
-# histograms capture exemplars through this hook (registered here, not in
-# metrics.py, to keep metrics import-independent of the tracer)
-metrics.set_exemplar_source(current_span_id)
 
 
 def span(name: str, *, cat: str = "fluxsieve", **args):

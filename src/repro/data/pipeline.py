@@ -247,7 +247,8 @@ class IngestPipeline:
                 faults.fire("ingest.append", n=len(out))
                 self.store.append(out)
                 if self.wal is not None:
-                    self.wal.truncate(self.store.sealed_rows)
+                    with telemetry.span("ingest/wal_truncate", cat="ingest"):
+                        self.wal.truncate(self.store.sealed_rows)
         t2 = time.perf_counter()
         _STAGE_HIST["finalize_wait"].observe(t1 - t0)
         _STAGE_HIST["store"].observe(t2 - t1)
@@ -359,7 +360,9 @@ class IngestPipeline:
                     faults.fire("ingest.append", n=n)
                     self.store.append(batch)
                     if self.wal is not None:
-                        self.wal.truncate(self.store.sealed_rows)
+                        with telemetry.span("ingest/wal_truncate",
+                                            cat="ingest"):
+                            self.wal.truncate(self.store.sealed_rows)
                 store_s = time.perf_counter() - ts
                 t.store_s += store_s
                 _STAGE_HIST["store"].observe(store_s)

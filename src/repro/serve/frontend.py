@@ -451,7 +451,9 @@ class FrontEnd:
                 resp = {"status": 500, "error": f"{type(e).__name__}: {e}"}
             resp["id"] = req.get("id")
             try:
-                send_frame(conn, resp)
+                with telemetry.span("serve/respond", cat="serve",
+                                    request=resp["id"]):
+                    send_frame(conn, resp)
             except OSError as e:    # client went away mid-response
                 telemetry.suppressed("serve.respond", e)
                 return
@@ -491,23 +493,28 @@ class FrontEnd:
             _rejection(route, "admission").inc()
             return {"status": 429, "error": "rate limit exceeded",
                     "reason": "admission"}
+        rid = req.get("id")
         deadline_s = float(req.get("deadline_ms",
                                    self.default_deadline_s * 1e3)) / 1e3
         deadline = time.monotonic() + deadline_s
-        with self._queue_lock:
-            if self._waiting >= self.max_queue:
-                _shed_counter(route, "queue_full").inc()
-                return {"status": 503, "error": "server overloaded",
-                        "reason": "queue_full"}
-            self._waiting += 1
-            _QUEUED.inc()
-        try:
-            got = self._inflight_sem.acquire(
-                timeout=max(0.0, deadline - time.monotonic()))
-        finally:
+        with telemetry.span("serve/queue_wait", cat="serve",
+                            request=rid) as wait:
             with self._queue_lock:
-                self._waiting -= 1
-                _QUEUED.dec()
+                if self._waiting >= self.max_queue:
+                    _shed_counter(route, "queue_full").inc()
+                    wait.set(outcome="queue_full")
+                    return {"status": 503, "error": "server overloaded",
+                            "reason": "queue_full"}
+                self._waiting += 1
+                _QUEUED.inc()
+            try:
+                got = self._inflight_sem.acquire(
+                    timeout=max(0.0, deadline - time.monotonic()))
+            finally:
+                with self._queue_lock:
+                    self._waiting -= 1
+                    _QUEUED.dec()
+            wait.set(outcome="admitted" if got else "deadline")
         if not got:
             _shed_counter(route, "deadline").inc()
             return {"status": 504, "error": "deadline exceeded in queue",
@@ -516,7 +523,7 @@ class FrontEnd:
         t0 = time.perf_counter()
         try:
             with telemetry.span("serve/request", cat="serve", route=route,
-                                client=client):
+                                client=client, request=rid):
                 faults.fire("serve.handle", route=route, client=client)
                 resp = self._dispatch(route, req)
             _latency_hist(route).observe(time.perf_counter() - t0)

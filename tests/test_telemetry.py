@@ -325,6 +325,115 @@ def test_end_to_end_snapshot_covers_all_five_planes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# Spans inside the program: one served request, one ingest seal
+# ---------------------------------------------------------------------------
+
+def test_span_set_adds_args_and_null_span_takes_them():
+    tr = Tracer()
+    with tr.span("outer", n=1) as sp:
+        sp.set(outcome="admitted")
+    assert tr.spans()[0]["args"]["outcome"] == "admitted"
+    assert tr.spans()[0]["args"]["n"] == 1
+    telemetry.set_enabled(False)
+    try:
+        with tr.span("off") as sp:
+            sp.set(outcome="ignored")
+    finally:
+        telemetry.set_enabled(True)
+    assert len(tr) == 1
+
+
+def _spans_of(names, *, want, timeout_s=5.0):
+    """The tracer's finished spans once every name in ``want`` is there
+    (a server thread records its last span after the client has read the
+    response)."""
+    t_end = time.monotonic() + timeout_s
+    while True:
+        spans = [s for s in telemetry.trace.TRACER.spans()
+                 if s["name"] in names]
+        if want <= {s["name"] for s in spans} or time.monotonic() > t_end:
+            return spans
+        time.sleep(0.01)
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    from repro.serve.frontend import FrontEnd
+    w = make_world(tmp_path_factory.mktemp("served"))
+    with FrontEnd(w["engine"]) as fe:
+        yield fe
+
+
+@pytest.mark.parametrize("mode", ["count", "ids"])
+def test_served_request_spans_share_the_wire_id(served, mode):
+    """Queue wait, request and response carry the request's wire id; the
+    engine's stages nest under its ``query/execute`` span, and only a
+    query that reads rows materialises them."""
+    from repro.serve.frontend import ServeClient
+    telemetry.reset()
+    rid = 4100 + len(mode)
+    with ServeClient(served.host, served.port) as client:
+        # the dense rule and a planted term: the stacked bitmap path
+        resp = client.request("query", id=rid, mode=mode,
+                              terms=[["content1", "a"],
+                                     ["content2", "HIGHneedle2x"]])
+    assert resp["status"] == 200 and resp["id"] == rid
+    stages = {"query/plan", "query/arrangement", "query/stacked_dispatch",
+              "query/device_wait", "query/materialize"}
+    serve = {"serve/queue_wait", "serve/request", "serve/respond"}
+    spans = _spans_of(serve | stages | {"query/execute"},
+                      want=serve | {"query/execute"})
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    for name in serve:
+        (span,) = by_name[name]
+        assert span["args"]["request"] == rid, name
+    assert by_name["serve/queue_wait"][0]["args"]["outcome"] == "admitted"
+    (execute,) = by_name["query/execute"]
+    assert execute["args"]["parent"] == \
+        by_name["serve/request"][0]["args"]["id"]
+    children = {s["name"] for s in spans
+                if s["args"].get("parent") == execute["args"]["id"]}
+    assert {"query/plan", "query/arrangement", "query/stacked_dispatch",
+            "query/device_wait"} <= children
+    assert ("query/materialize" in children) == (mode == "ids")
+    (arrangement,) = by_name["query/arrangement"]
+    assert arrangement["args"]["hit"] in (True, False)
+
+
+def test_ingest_spans_seal_spill_and_wal_truncation(tmp_path):
+    """A rooted ingest with the WAL on: each seal holds its segment's
+    spill, and each stored batch truncates the journal once."""
+    spec = WorkloadSpec(num_records=3000, ultra_rate=1e-3, high_rate=1e-2,
+                        seed=5, text_width=128)
+    rules = RuleSet(tuple(Rule(i, t.term, t.term, fields=(t.fieldname,))
+                          for i, t in enumerate(spec.planted)))
+    proc = StreamProcessor(compile_bundle(rules, spec.content_fields),
+                           backend="dfa_ref")
+    store = SegmentStore(segment_size=1000, root=tmp_path)
+    telemetry.reset()
+    IngestPipeline(LogGenerator(spec), store, proc, wal=True).run(
+        batch_size=500)
+    spans = telemetry.trace.TRACER.spans()
+
+    def named(name):
+        return [s for s in spans if s["name"] == name]
+
+    seals, spills = named("store/seal"), named("store/spill")
+    assert len(seals) == len(spills) == 3
+    assert [s["args"]["rows"] for s in seals] == [1000] * 3
+    assert sorted(s["args"]["parent"] for s in spills) == \
+        sorted(s["args"]["id"] for s in seals)
+    stores, truncs = named("ingest/store"), named("ingest/wal_truncate")
+    assert len(stores) == len(truncs) == 6
+    assert sorted(s["args"]["parent"] for s in truncs) == \
+        sorted(s["args"]["id"] for s in stores)
+    assert {s["args"]["parent"] for s in seals} <= \
+        {s["args"]["id"] for s in stores}
+
+
+# ---------------------------------------------------------------------------
 # Satellite: orphan-dir sweep (crash between spill and manifest commit)
 # ---------------------------------------------------------------------------
 
