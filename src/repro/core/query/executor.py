@@ -126,8 +126,8 @@ class PlanExecutor:
     scan (fused-capable backends batch all scan segments into one
     dispatch).  ``device_counts`` selects the device-side per-segment
     count reduction for count-mode queries: ``"auto"`` enables it on real
-    accelerators only (on XLA CPU the scatter reduction measurably costs
-    more than transferring the mask — PR 3), ``True``/``False`` force it.
+    accelerators only (on XLA CPU the reduction measurably costs more
+    than transferring the mask), ``True``/``False`` force it.
     Thread-safe; ``workers > 1`` scans host-path segments concurrently
     (the intra-query parallelism axis of Figs 6-9)."""
 
@@ -236,8 +236,8 @@ class PlanExecutor:
                     block_n=self.block_n, with_counts=with_counts)
             _STACKED_DISPATCH.inc()
             # the ONE counted D2H per query: on accelerators the
-            # device-side segment_sum shrinks it from N bytes to S ints;
-            # on XLA CPU the mask transfer is the measured win
+            # device-side one-hot count reduction shrinks it from N bytes
+            # to S ints; on XLA CPU the mask transfer is the measured win
             with telemetry.span("query/device_wait", cat="query",
                                 counts=with_counts):
                 if with_counts:

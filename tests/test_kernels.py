@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle vs the
 host numpy reference, swept over shapes/dtypes per the task spec."""
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,7 +12,8 @@ from repro.core.records import encode_texts
 from repro.kernels.bitmap_filter.ops import (bitmap_count, bitmap_match,
                                              bitmap_query_stacked,
                                              bitmap_query_words,
-                                             bitmap_select)
+                                             bitmap_select,
+                                             _word_query_dispatch)
 from repro.kernels.bitmap_filter.ref import bitmap_filter_ref
 from repro.kernels.dfa_scan.ops import dfa_scan
 from repro.kernels.shift_or.ops import compile_shift_or, shift_or_match
@@ -118,13 +121,25 @@ def test_bitmap_filter_shapes(n, w):
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
-@pytest.mark.parametrize("p", [1, 2, 3])
-def test_bitmap_query_stacked_multi_segment(backend, p):
+@pytest.mark.parametrize("p, nseg, min_len, max_len, block_n", [
+    pytest.param(1, 4, 1, 40, 8, id="1"),
+    pytest.param(2, 4, 1, 40, 8, id="2"),
+    pytest.param(3, 4, 1, 40, 8, id="3"),
+    # 20 ragged segments bucket to 32 slots, rows pad to a block multiple
+    pytest.param(2, 20, 1, 400, 128, id="2-20ragged"),
+    # above 2**16 rows, so an exact count cannot hide in a narrow type
+    pytest.param(3, 20, 3300, 3700, 1024, id="3-wide"),
+    # 100 segments bucket to 128 slots: the split one-hot layout
+    pytest.param(2, 100, 1, 80, 128, id="2-100seg"),
+])
+def test_bitmap_query_stacked_multi_segment(backend, p, nseg, min_len,
+                                            max_len, block_n):
     """The multi-segment conjunctive entries (full-width masks AND the
     word-sliced fast path) agree with the numpy AND-of-any semantics across
-    ragged segment sizes, and padded rows/slots never contribute."""
+    ragged segment sizes, counts equal the per-segment numpy sums exactly,
+    and padded rows/slots never contribute."""
     rng = np.random.default_rng(p * 10 + (backend == "pallas"))
-    lens = [int(rng.integers(1, 40)) for _ in range(4)]
+    lens = [int(rng.integers(min_len, max_len)) for _ in range(nseg)]
     N, W = sum(lens), 3
     bm = rng.integers(0, 2**32, size=(N, W), dtype=np.uint32)
     bm[rng.random(N) < 0.5] = 0
@@ -132,30 +147,47 @@ def test_bitmap_query_stacked_multi_segment(backend, p):
     masks = np.zeros((p, W), np.uint32)
     for i, r in enumerate(rids):
         masks[i, r // 32] = np.uint32(1) << np.uint32(r % 32)
-    row_seg = np.repeat(np.arange(4, dtype=np.int32), lens)
+    row_seg = np.repeat(np.arange(nseg, dtype=np.int32), lens)
     want = (((bm[:, None, :] & masks[None]) != 0).any(-1)).all(-1)
-    want_counts = [int(want[row_seg == s].sum()) for s in range(4)]
+    want_counts = [int(want[row_seg == s].sum()) for s in range(nseg)]
+    slots = 1 << (nseg - 1).bit_length()
 
     m, c = bitmap_query_stacked(jnp.asarray(bm), jnp.asarray(masks),
-                                jnp.asarray(row_seg), num_segments=4,
-                                backend=backend, block_n=8)
+                                jnp.asarray(row_seg), num_segments=nseg,
+                                backend=backend, block_n=block_n)
     np.testing.assert_array_equal(np.asarray(m)[:N], want)
     assert not np.asarray(m)[N:].any()          # padded rows never match
-    assert np.asarray(c)[:4].tolist() == want_counts
-    assert not np.asarray(c)[4:].any()          # padded slots stay zero
+    assert np.asarray(c).shape == (slots,)
+    assert np.asarray(c)[:nseg].tolist() == want_counts
+    assert not np.asarray(c)[nseg:].any()       # padded slots stay zero
 
     words = jnp.asarray((rids // 32).astype(np.int32))
     cols = jnp.asarray(np.ascontiguousarray(bm[:, np.asarray(rids) // 32]))
     bits = jnp.asarray(masks[np.arange(p), np.asarray(rids) // 32])
     m2, c2 = bitmap_query_words(cols, bits, jnp.asarray(row_seg),
-                                num_segments=4, backend=backend, block_n=8)
+                                num_segments=nseg, backend=backend,
+                                block_n=block_n)
     np.testing.assert_array_equal(np.asarray(m2)[:N], want)
-    assert np.asarray(c2)[:4].tolist() == want_counts
+    assert np.asarray(c2)[:nseg].tolist() == want_counts
+    assert not np.asarray(c2)[nseg:].any()
     m3, c3 = bitmap_query_words(cols, bits, jnp.asarray(row_seg),
-                                num_segments=4, backend=backend, block_n=8,
-                                with_counts=False)
+                                num_segments=nseg, backend=backend,
+                                block_n=block_n, with_counts=False)
     np.testing.assert_array_equal(np.asarray(m3)[:N], want)
     assert c3 is None
+
+
+@pytest.mark.parametrize("num_segments", [32, 256])
+def test_word_query_counts_compile_without_scatter(num_segments):
+    """The per-segment count reduction is a dense one-hot contraction: the
+    compiled count path holds no scatter, whose colliding slot updates
+    serialise (every row of a segment lands on one slot)."""
+    n = 1 << 12
+    hlo = _word_query_dispatch.lower(
+        jnp.zeros((n, 2), jnp.uint32), jnp.ones((2,), jnp.uint32),
+        jnp.zeros((n,), jnp.int32), num_segments=num_segments,
+        backend="ref", block_n=1024, with_counts=True).compile().as_text()
+    assert not re.search(r"\sscatter\(", hlo)   # the op, not a metadata name
 
 
 def test_bitmap_select_compaction():
